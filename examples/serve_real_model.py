@@ -22,7 +22,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.policies import LazyBatching
 from repro.core.slack import SlackPredictor
-from repro.serving.engine import JaxEngine
+from repro.serving.engine import JaxEngine, reference_generate
 from repro.serving.npu_model import NPUPerfModel, TPU_V5E
 from repro.serving.session import HandleState, ServingSession
 from repro.serving.workload import fixed_length, from_model_config, LengthDist
@@ -92,8 +92,8 @@ def main():
         got = engine.states[r.rid].generated[:r.decode_len]
         assert streamed[r.rid][:r.decode_len] == got == h.tokens[:r.decode_len], \
             f"rid={r.rid}: streamed tokens diverge from batch execute_run"
-        ref = _reference_generate(ref_engine, wl, prompts[r.rid],
-                                  r.decode_len)
+        ref = reference_generate(ref_engine, wl, prompts[r.rid],
+                                 r.decode_len)
         if got != ref:
             mismatches += 1
             print(f"  rid={r.rid}: engine {got} != reference {ref}")
@@ -101,24 +101,6 @@ def main():
         raise SystemExit(f"{mismatches} requests diverged from reference!")
     print(f"all {args.n} generations match the unbatched reference — "
           "lazy batching preserved results exactly.")
-
-
-def _reference_generate(engine: JaxEngine, wl, prompt, n_tokens: int):
-    """Generate in isolation through the same engine (batch of 1, no
-    preemption): the ground truth LazyBatching must reproduce."""
-    rng = np.random.default_rng(123)
-    req = wl.sample_request(rng, 0.0)
-    # rebuild the node sequence for this exact prompt/decode length
-    seq, prefix_len, cycle_len = wl.build_sequence(len(prompt), n_tokens)
-    req.sequence, req.prefix_len, req.cycle_len = seq, prefix_len, cycle_len
-    req.prompt_len, req.decode_len = len(prompt), n_tokens
-    engine.register(req, prompt)
-    from repro.core.request import SubBatch
-    sb = SubBatch([req])
-    while not req.done:
-        engine.execute("m", sb, req.next_node_id)
-        sb.advance(0.0)
-    return engine.states[req.rid].generated[:n_tokens]
 
 
 if __name__ == "__main__":

@@ -39,9 +39,11 @@ from ..serving.npu_model import NPUPerfModel, PAPER_NPU, TPU_V5E
 from ..serving.server import SimExecutor
 from ..serving.session import ServingSession
 from ..serving.workload import get_workload
+from ..compile_cache import setup_compile_cache
 from .serve import (_jax_engine, _session_kwargs, _split_mem_slots,
-                    _wrap_faults, build_policy, parse_mem_shares,
-                    parse_models, parse_shed_priorities, parse_tiers)
+                    _wrap_faults, add_jax_engine_args, build_policy,
+                    parse_mem_shares, parse_models, parse_shed_priorities,
+                    parse_tiers)
 
 
 def build_session(args) -> ServingSession:
@@ -216,9 +218,11 @@ def main(argv=None) -> int:
     ap.add_argument("--shed-priorities", default=None)
     ap.add_argument("--hw", default="paper", choices=["paper", "v5e"])
     ap.add_argument("--seed", type=int, default=0)
+    add_jax_engine_args(ap)
     args = ap.parse_args(argv)
     if args.sla is None:
         args.sla = 60.0 if args.engine == "jax" else 0.1
+    setup_compile_cache()
 
     app = build_app(args)
     asyncio.run(app.run())

@@ -8,6 +8,9 @@ run-commit scheduling core and print the same summary line:
   * ``--engine jax``  — the real node-level JAX engine on a reduced model
     (CPU-runnable end-to-end; wall-clock time, so pick an SLA in seconds
     that matches your hardware — the default is auto-scaled).
+    ``--full-width`` serves the published config instead, with
+    ``--max-len``, ``--prompt-lens`` and ``--decode-lens`` sizing the
+    arena and the traffic (``chip_smoke.py`` drives this on a TPU).
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
       --policy lazyb --rate 200 --engine sim
@@ -58,6 +61,7 @@ import sys
 
 import numpy as np
 
+from ..compile_cache import setup_compile_cache
 from ..configs import ARCHITECTURES, get_config
 from ..core.arbiter import LeastSlackArbiter, RoundRobinArbiter
 from ..core.policies import (CellularBatching, GraphBatching, LazyBatching,
@@ -118,22 +122,60 @@ def parse_models(spec: str):
     return [(n, s / total) for n, s in pairs]
 
 
-def _jax_workload(cfg):
-    # short prompts / few decode steps: CPU wall-clock budget
-    return from_model_config(
-        cfg, prompt_dist=LengthDist((6, 8, 10, 12), (0.25,) * 4),
-        decode_dist=LengthDist((2, 3, 4, 5), (0.25,) * 4))
+def _lengths(spec: str):
+    """Parse ``n[,n...]`` into a uniform LengthDist."""
+    try:
+        lengths = tuple(int(part) for part in spec.split(","))
+    except ValueError:
+        lengths = ()
+    if not lengths or min(lengths) < 1:
+        raise SystemExit(f"length list {spec!r} must be positive ints "
+                         f"n[,n...]")
+    return LengthDist(lengths, (1.0 / len(lengths),) * len(lengths))
+
+
+def add_jax_engine_args(ap):
+    """Flags sizing the jax engine and its traffic (serve and gateway)."""
+    ap.add_argument("--full-width", action="store_true",
+                    help="jax engine: serve the published config instead "
+                         "of its reduced CPU-sized variant")
+    ap.add_argument("--max-len", type=int, default=64,
+                    help="jax engine: KV arena length per request slot "
+                         "(above 512 it must be a multiple of 512, the "
+                         "decode kernel's KV block)")
+    # defaults: short prompts / few decode steps (CPU wall-clock budget)
+    ap.add_argument("--prompt-lens", default="6,8,10,12",
+                    help="jax engine: prompt lengths, drawn uniformly")
+    ap.add_argument("--decode-lens", default="2,3,4,5",
+                    help="jax engine: output lengths, drawn uniformly")
+
+
+def jax_model_spec(name, args):
+    """(config, max_len) the jax engine serves for ``name``: the reduced
+    config unless ``--full-width``, at ``--max-len``."""
+    arch = name if name in ARCHITECTURES else "llama3.2-1b"
+    cfg = get_config(arch)
+    if not args.full_width:
+        cfg = cfg.reduced()
+    if args.max_len > 512 and args.max_len % 512:
+        raise SystemExit(f"--max-len {args.max_len}: above 512 it must be "
+                         f"a multiple of 512 (the decode kernel's KV block)")
+    return cfg, args.max_len
 
 
 def _jax_engine(name, args, max_slots=None):
-    """One reduced-model engine + its served workload for ``name``.
-    ``max_slots`` is THIS engine's arena cap (per-model engines own
-    disjoint pools — multi-tenant callers split the device budget)."""
+    """One engine + its served workload for ``name``. ``max_slots`` is
+    THIS engine's arena cap (per-model engines own disjoint pools —
+    multi-tenant callers split the device budget)."""
     from ..serving.engine import JaxEngine
-    arch = name if name in ARCHITECTURES else "llama3.2-1b"
-    cfg = get_config(arch).reduced()
-    return (JaxEngine(cfg, max_len=64, seed=args.seed, max_slots=max_slots),
-            _jax_workload(cfg))
+    cfg, max_len = jax_model_spec(name, args)
+    prompts, decodes = _lengths(args.prompt_lens), _lengths(args.decode_lens)
+    if max(prompts.lengths) + max(decodes.lengths) > max_len:
+        raise SystemExit(f"longest prompt + output exceeds --max-len "
+                         f"{max_len}: its KV would not fit the arena slot")
+    wl = from_model_config(cfg, prompt_dist=prompts, decode_dist=decodes)
+    return (JaxEngine(cfg, max_len=max_len, seed=args.seed,
+                      max_slots=max_slots), wl)
 
 
 def _split_mem_slots(mem_slots, shares, mem_shares):
@@ -281,6 +323,7 @@ def _run_session(session, trace, label, args):
     if args.json_out:
         dump_json(args.json_out, stats, session.log, args, session=session)
     _check_gates(session, stats, args)
+    return session
 
 
 def print_summary(wl_name: str, args, stats, log):
@@ -366,7 +409,8 @@ def dump_json(path: str, stats, log, args, session=None):
     print(f"wrote {path}")
 
 
-def main():
+def main(argv=None):
+    """Run the launcher; returns the drained session."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="transformer",
                     help="paper workload or assigned architecture id")
@@ -439,7 +483,9 @@ def main():
     ap.add_argument("--json-out", default=None,
                     help="write the full ServeStats (summary + per-class + "
                          "per-model) to this JSON file")
-    args = ap.parse_args()
+    add_jax_engine_args(ap)
+    args = ap.parse_args(argv)
+    setup_compile_cache()
 
     perf = NPUPerfModel(PAPER_NPU if args.hw == "paper" else TPU_V5E)
     if args.sla is None:
@@ -505,9 +551,8 @@ def main():
             with_sla_classes(trace, parse_tiers(args.sla_tiers),
                              seed=args.seed)
         # submissions route on each request's mixture model tag
-        _run_session(session, trace,
-                     "+".join(name for name, _ in shares), args)
-        return
+        return _run_session(session, trace,
+                            "+".join(name for name, _ in shares), args)
 
     # ---- single-model path ---------------------------------------------
     if args.engine == "jax":
@@ -527,10 +572,10 @@ def main():
 
     policy = build_policy(args.policy, wl, perf, args.sla, args.max_batch,
                           args.window)
-    _run_session(session=ServingSession(policy, _wrap_faults(backend, args),
-                                        seed=args.seed,
-                                        **_session_kwargs(args)),
-                 trace=trace, label=wl.name, args=args)
+    return _run_session(
+        session=ServingSession(policy, _wrap_faults(backend, args),
+                               seed=args.seed, **_session_kwargs(args)),
+        trace=trace, label=wl.name, args=args)
 
 
 if __name__ == "__main__":

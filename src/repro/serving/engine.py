@@ -90,7 +90,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1140,3 +1140,38 @@ class JaxEngine(Backend):
             lambda p, l: (l[0] if not _is_time_leaf(p) and l.ndim >= 1
                           and l.shape[0] == 1 else l),
             padded)
+
+
+def reference_generate(engine: JaxEngine, wl, prompt, n_tokens: int, *,
+                       forced: Optional[List[int]] = None,
+                       on_head: Optional[Callable] = None) -> List[int]:
+    """Generate ``n_tokens`` for ``prompt`` in isolation through
+    ``engine``'s single-node path (batch of 1, no preemption): the ground
+    truth that batched serving must reproduce.
+
+    ``forced`` teacher-forces the run: step i still computes its greedy
+    pick, but feeds ``forced[i]`` onward, so the reference follows a given
+    generation step by step. ``on_head(x)`` sees each step's final hidden
+    state ``(1, d_model)`` before the head picks from it."""
+    rng = np.random.default_rng(123)
+    req = wl.sample_request(rng, 0.0)
+    # rebuild the node sequence for this exact prompt/decode length
+    seq, prefix_len, cycle_len = wl.build_sequence(len(prompt), n_tokens)
+    req.sequence, req.prefix_len, req.cycle_len = seq, prefix_len, cycle_len
+    req.prompt_len, req.decode_len = len(prompt), n_tokens
+    engine.register(req, prompt)
+    st = engine.state(req)
+    sb = SubBatch([req])
+    picks = []
+    while not req.done:
+        node_id = req.next_node_id
+        head = node_id == "head"
+        if head and on_head is not None:
+            on_head(engine._batched_x([req], [st])[1])
+        engine.execute("m", sb, node_id)
+        if head:
+            picks.append(st.generated[-1])
+            if forced is not None:
+                st.next_token = st.generated[-1] = int(forced[len(picks) - 1])
+        sb.advance(0.0)
+    return picks

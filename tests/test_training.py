@@ -53,7 +53,11 @@ def test_cosine_schedule_shape():
 
 
 @settings(max_examples=30, deadline=None)
-@given(st.lists(st.floats(-100, 100, allow_nan=False), min_size=1, max_size=8),
+# float32 values without subnormals: XLA flushes those to zero, numpy
+# keeps them
+@given(st.lists(st.floats(-100, 100, allow_nan=False, allow_subnormal=False,
+                          width=32),
+                min_size=1, max_size=8),
        st.floats(0.1, 10))
 def test_clip_bounds_global_norm(vals, max_norm):
     g = {"x": jnp.asarray(vals, jnp.float32)}
