@@ -258,9 +258,13 @@ VIRTUAL_TIME_SUFFIXES = (
 #: still propagates out of any NON-audited module into virtual-time
 #: code). Adding a prefix here is a reviewed audit decision — the
 #: boundary module must keep wall time out of SLA/latency arithmetic,
-#: as ``gateway/bridge.py``'s module docstring spells out.
+#: as ``gateway/bridge.py``'s module docstring spells out. The span and
+#: counter recorder (``serving/tracing.py``) is the other: the session
+#: and the engine call into it, and it returns them nothing they could
+#: put on the session clock.
 WALLCLOCK_AUDITED_PREFIXES = (
     "repro/serving/gateway/",
+    "repro/serving/tracing.py",
 )
 
 

@@ -143,4 +143,5 @@ def ragged_decode_attention(q, k, v, lengths, *, slots=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="ragged_decode_attention",
     )(slots, lengths, q, k, v)

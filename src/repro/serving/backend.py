@@ -319,17 +319,6 @@ class MultiBackend(Backend):
 
 
 @dataclass
-class NodeLat:
-    """Per-node-id (or per-fused-run-span) latency accumulator."""
-    count: int = 0
-    total: float = 0.0
-
-    @property
-    def mean(self) -> float:
-        return self.total / max(1, self.count)
-
-
-@dataclass
 class ServerLog:
     nodes_executed: int = 0
     runs_executed: int = 0
@@ -339,18 +328,8 @@ class ServerLog:
     # injected or real); the faulted dispatch's detection latency is in
     # busy_time but its nodes are NOT in nodes_executed — nothing ran
     faults: int = 0
-    # per-node-id latency breakdown; fused runs (no per-node observability)
-    # are keyed by their span, e.g. "D0..head" — making run-fusion wins
-    # visible per phase next to the per-node entries. Multi-model sessions
-    # prefix keys with the model name ("llama:D0..head").
-    node_lat: Dict[str, NodeLat] = field(default_factory=dict)
     # per-model share of the (single) device-time clock
     busy_by_model: Dict[str, float] = field(default_factory=dict)
-
-    def record(self, key: str, latency: float, n: int = 1):
-        ent = self.node_lat.setdefault(key, NodeLat())
-        ent.count += n
-        ent.total += latency
 
     @property
     def avg_batch_size(self) -> float:

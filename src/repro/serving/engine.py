@@ -101,6 +101,7 @@ from ..core.request import Request, SubBatch
 from ..models import layers as L
 from ..models.cost import _layer_kinds
 from ..models.model import Model, RuntimeFlags, _index, _stack
+from . import tracing
 from .backend import Backend, BackendOOMError
 
 # cache leaves whose leading (post-batch) axis is the KV time axis
@@ -497,8 +498,11 @@ class JaxEngine(Backend):
 
     def _note_trace(self):
         """Called from INSIDE jitted bodies: executes only at trace time,
-        so each call is exactly one retrace/compile."""
+        so each call is exactly one retrace/compile (and, when tracing,
+        where the dispatch that compiles gets its compile span)."""
         self._san_retraces += 1
+        if tracing.ON:
+            tracing.compiling()
 
     def on_finished(self, model, reqs: Sequence[Request]) -> None:
         self._release_slots(reqs)
@@ -621,7 +625,7 @@ class JaxEngine(Backend):
         if key not in self._jit_cache:
             kind, window = self._kind_window(i)
 
-            def fn(bp, x):
+            def prefill(bp, x):
                 self._note_trace()
                 positions = jnp.arange(x.shape[1])[None, :]
                 x, cache = self.model.apply_block_dense(
@@ -631,7 +635,7 @@ class JaxEngine(Backend):
                     cache = cache[0]
                 return x, cache
 
-            self._jit_cache[key] = jax.jit(fn)
+            self._jit_cache[key] = jax.jit(prefill)
         return self._jit_cache[key]
 
     def _fn_prefill_arena(self, si: int):
@@ -642,7 +646,7 @@ class JaxEngine(Backend):
         if key not in self._jit_cache:
             kind, window, _, _ = self._spans[si]
 
-            def fn(bp, arena, x, row):
+            def prefill_arena(bp, arena, x, row):
                 self._note_trace()
                 positions = jnp.arange(x.shape[1])[None, :]
                 x, cache = self.model.apply_block_dense(
@@ -664,7 +668,8 @@ class JaxEngine(Backend):
             # the donated arena is updated in-place instead of copying all
             # rows per dispatch (backends without donation support fall
             # back to a copy with a warning)
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(1,))
+            self._jit_cache[key] = jax.jit(prefill_arena,
+                                           donate_argnums=(1,))
         return self._jit_cache[key]
 
     def _fn_decode(self, i: int):
@@ -672,12 +677,12 @@ class JaxEngine(Backend):
         if key not in self._jit_cache:
             kind, window = self._kind_window(i)
 
-            def fn(bp, x, cache, pos):
+            def decode(bp, x, cache, pos):
                 self._note_trace()
                 return self.model.apply_block_decode(
                     bp, x, cache, pos, kind, window=window)
 
-            self._jit_cache[key] = jax.jit(fn)
+            self._jit_cache[key] = jax.jit(decode)
         return self._jit_cache[key]
 
     def _fn_decode_arena(self, si: int):
@@ -689,24 +694,25 @@ class JaxEngine(Backend):
         if key not in self._jit_cache:
             kind, window, _, _ = self._spans[si]
 
-            def fn(bp, arena, x, pos, slots, off):
+            def decode_arena(bp, arena, x, pos, slots, off):
                 self._note_trace()
                 return self.model.apply_block_decode(
                     bp, x, arena, pos, kind, window=window,
                     slots=slots + off)
 
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(1,))
+            self._jit_cache[key] = jax.jit(decode_arena,
+                                           donate_argnums=(1,))
         return self._jit_cache[key]
 
     def _fn_head(self):
         if "head" not in self._jit_cache:
-            def fn(params, x):
+            def head(params, x):
                 self._note_trace()
                 h = L.rms_norm(x, params["final_norm"], self.cfg.norm_eps)
                 logits = self.model.unembed(params, h)
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-            self._jit_cache["head"] = jax.jit(fn)
+            self._jit_cache["head"] = jax.jit(head)
         return self._jit_cache["head"]
 
     # ------------------------------------------------------------------
@@ -740,7 +746,8 @@ class JaxEngine(Backend):
         key = ("mega", lo, hi, with_head, ctx)
         if key not in self._jit_cache:
 
-            def fn(params, span_params, arenas, entry, pos, slots, offs):
+            def decode_megastep(params, span_params, arenas, entry, pos,
+                                slots, offs):
                 self._note_trace()
                 x = (self.model.embed(params, entry) if lo == 0 else entry)
                 new_arenas = list(arenas)
@@ -763,7 +770,8 @@ class JaxEngine(Backend):
                     out = x
                 return out, new_arenas
 
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(2,))
+            self._jit_cache[key] = jax.jit(decode_megastep,
+                                           donate_argnums=(2,))
         return self._jit_cache[key]
 
     def _fn_prefill_run(self, lo: int, hi: int, embed: bool):
@@ -775,7 +783,7 @@ class JaxEngine(Backend):
         key = ("prefill_run", lo, hi, embed)
         if key not in self._jit_cache:
 
-            def fn(params, span_params, arenas, entry, slots, offs):
+            def prefill_run(params, span_params, arenas, entry, slots, offs):
                 self._note_trace()
                 x = self.model.embed(params, entry) if embed else entry
                 positions = jnp.arange(x.shape[1])[None, :]
@@ -804,7 +812,7 @@ class JaxEngine(Backend):
                         window=window, positions=positions, write=write)
                 return x, new_arenas
 
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=(2,))
+            self._jit_cache[key] = jax.jit(prefill_run, donate_argnums=(2,))
         return self._jit_cache[key]
 
     # ------------------------------------------------------------------
@@ -882,6 +890,7 @@ class JaxEngine(Backend):
         if has_emb:
             fn = self._fn_prefill_run(0, layers[-1], embed=True)
             for members, Lb in self._prefill_groups(reqs, sts):
+                plan = tracing.ON and tracing.begin(tracing.ENGINE_PLAN)
                 Bg = len(members)
                 Bp = _pow2(Bg)
                 toks = np.zeros((Bp, Lb), np.int32)
@@ -889,9 +898,19 @@ class JaxEngine(Backend):
                 for bi, (r, st) in enumerate(members):
                     toks[bi, :st.prefill_len] = st.prompt_np[:st.prefill_len]
                     slots[bi] = self.slot_of(r)   # may grow the arena first
+                entry, slots, offs = (jnp.asarray(toks), jnp.asarray(slots),
+                                      self._offs())
+                if plan:
+                    tracing.end(plan)
+                    tracing.count(tracing.PREFILL_TOKENS,
+                                  sum(st.prefill_len for _, st in members))
+                    tracing.count(tracing.PREFILL_POSITIONS, Bp * Lb)
+                sp = tracing.ON and tracing.begin(tracing.ENGINE_DISPATCH,
+                                                  program="prefill_run")
                 x, self.arenas = fn(self.params, self._span_params,
-                                    self.arenas, jnp.asarray(toks),
-                                    jnp.asarray(slots), self._offs())
+                                    self.arenas, entry, slots, offs)
+                if sp:
+                    tracing.end(sp)
                 for bi, (r, st) in enumerate(members):
                     st.x = (None if last
                             else x[bi:bi + 1, :st.prefill_len])
@@ -900,9 +919,16 @@ class JaxEngine(Backend):
             fn = self._fn_prefill_run(layers[0], layers[-1], embed=False)
             for r, st in zip(reqs, sts):
                 slots = jnp.asarray([self.slot_of(r)], jnp.int32)
+                sp = tracing.ON and tracing.begin(tracing.ENGINE_DISPATCH,
+                                                  program="prefill_run")
+                if sp:
+                    tracing.count(tracing.PREFILL_TOKENS, st.prefill_len)
+                    tracing.count(tracing.PREFILL_POSITIONS, st.prefill_len)
                 st.x, self.arenas = fn(self.params, self._span_params,
                                        self.arenas, st.x, slots,
                                        self._offs())
+                if sp:
+                    tracing.end(sp)
                 if last:
                     st.x = None
 
@@ -910,12 +936,23 @@ class JaxEngine(Backend):
         """Execute a committed run; returns ``(latency, None)`` — per-node
         latency is unobservable inside fused dispatches, by design."""
         if self.cache_mode != "arena" or not self.fused or len(node_ids) == 1:
+            run = tracing.ON and tracing.begin(tracing.ENGINE_RUN)
             s0 = self._san_host_syncs
             out = super().execute_run(model, sb, node_ids)
             self._san_max_syncs_per_run = max(
                 self._san_max_syncs_per_run, self._san_host_syncs - s0)
+            if run:
+                reqs = sb.live_requests
+                wl = reqs[0].workload
+                heads = sum(1 for n in node_ids
+                            if self._node_meta(wl, n)[0] == "head")
+                tracing.count(tracing.DECODE_CYCLES, heads)
+                tracing.end(run, rids=[r.rid for r in reqs], rows=len(reqs),
+                            padded=len(reqs), cycles=heads)
             return out
         t0 = time.perf_counter()
+        run = tracing.ON and tracing.begin(tracing.ENGINE_RUN)
+        plan = tracing.ON and tracing.begin(tracing.ENGINE_PLAN)
         reqs = sb.live_requests
         wl = reqs[0].workload
         sts = [self.states[r.rid] for r in reqs]
@@ -947,11 +984,14 @@ class JaxEngine(Backend):
                        + (1 if trailing else 0))
             ctx = min(_pow2(deepest), self.max_len)
         bkey = (rids, Bp)
+        if plan:
+            tracing.end(plan)
         for ch in chunks:
             if ch[0] == "prefill":
                 self._run_prefill_chunk(reqs, sts, ch[1])
                 continue
             _, lo, hi, with_head = ch
+            plan = tracing.ON and tracing.begin(tracing.ENGINE_PLAN)
             if slots is None:
                 slots = self._batched_slots(reqs, rids, padded_to=Bp)
                 if self._posbatch is not None and self._posbatch[0] == bkey:
@@ -971,9 +1011,15 @@ class JaxEngine(Backend):
                 entry = x_dev if x_dev is not None \
                     else self._entry_x(reqs, sts, B, Bp)
             fn = self._fn_mega(lo, hi, with_head, ctx)
+            offs = self._offs()
+            if plan:
+                tracing.end(plan)
+            sp = tracing.ON and tracing.begin(tracing.ENGINE_DISPATCH,
+                                              program="decode_megastep")
             out, self.arenas = fn(self.params, self._span_params,
-                                  self.arenas, entry, pos, slots,
-                                  self._offs())
+                                  self.arenas, entry, pos, slots, offs)
+            if sp:
+                tracing.end(sp)
             if with_head:
                 head_toks.append(out)
                 toks_dev = out
@@ -982,13 +1028,22 @@ class JaxEngine(Backend):
             else:
                 x_dev = out
         # ---- run boundary: the ONLY sync point -----------------------
-        if head_toks:
-            # reprolint: disable=sync-point
-            for arr in [np.asarray(t) for t in head_toks]:
-                for bi, st in enumerate(sts):
-                    st.next_token = int(arr[bi])  # reprolint: disable=sync-point
-                    st.generated.append(st.next_token)
-                    st.pos += 1
+        sp = tracing.ON and tracing.begin(tracing.ENGINE_SYNC)
+        # reprolint: disable=sync-point
+        arrs = [np.asarray(t) for t in head_toks]
+        jax.block_until_ready(self.arenas)  # reprolint: disable=sync-point
+        # token readback + arena fence at ONE run boundary are a single
+        # logical sync event: the one-sync-per-run contract
+        self._san_host_syncs += 1
+        self._san_max_syncs_per_run = max(self._san_max_syncs_per_run, 1)
+        if sp:
+            tracing.end(sp)
+        sp = tracing.ON and tracing.begin(tracing.ENGINE_EPILOGUE)
+        for arr in arrs:
+            for bi, st in enumerate(sts):
+                st.next_token = int(arr[bi])  # reprolint: disable=sync-point
+                st.generated.append(st.next_token)
+                st.pos += 1
         if n_heads and pos0 is not None:
             self._posbatch = (bkey, pos0 + n_heads)
             self._tokbatch = (bkey, toks_dev)
@@ -996,16 +1051,16 @@ class JaxEngine(Backend):
             self._xbatch = (rids, x_dev[:B])      # run ended mid-cycle
         else:
             self._xbatch = None
-        jax.block_until_ready(self.arenas)  # reprolint: disable=sync-point
-        # the whole epilogue (token readback + arena fence at ONE run
-        # boundary) is a single logical sync event — the PR 2 contract
-        self._san_host_syncs += 1
-        self._san_max_syncs_per_run = max(self._san_max_syncs_per_run, 1)
         self.nodes_executed += len(node_ids)
         self.runs_executed += 1
         n = len(node_ids)
         self._release_slots([r for r in reqs
                              if r.idx + n >= len(r.sequence)])  # final node
+        if sp:
+            tracing.end(sp)
+            tracing.count(tracing.DECODE_CYCLES, n_heads)
+            tracing.end(run, rids=list(rids), rows=B, padded=Bp,
+                        cycles=n_heads)
         return time.perf_counter() - t0, None
 
     def _entry_x(self, reqs, sts, B, Bp):
@@ -1023,6 +1078,8 @@ class JaxEngine(Backend):
         reqs = sb.live_requests
         outs = []
         phase, i = self._node_meta(reqs[0].workload, node_id)
+        disp = tracing.ON and tracing.begin(tracing.ENGINE_DISPATCH,
+                                            program=phase)
         if phase == "emb":
             for r in reqs:
                 st = self.state(r)
@@ -1102,11 +1159,16 @@ class JaxEngine(Backend):
         else:
             raise KeyError(f"unknown node {node_id!r}")
         self.nodes_executed += 1
+        if disp:
+            tracing.end(disp)
         # per-node dispatch fences every node — one sync event per NODE,
         # which is exactly why fused runs beat it (their whole run is one)
+        sp = tracing.ON and tracing.begin(tracing.ENGINE_SYNC)
         self._san_host_syncs += 1
         for o in outs:
             jax.block_until_ready(o)
+        if sp:
+            tracing.end(sp)
         # free arena slots of requests that just executed their final node
         # (on_finished() releases them too — both are idempotent — but this
         # covers direct engine driving without the server loop)

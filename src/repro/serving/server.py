@@ -27,7 +27,7 @@ import warnings
 from typing import Optional
 
 from ..core.policies import Policy
-from .backend import Backend, NodeLat, ServerLog, run_label
+from .backend import Backend, ServerLog, run_label
 from .metrics import ServeStats
 from .npu_model import NPUPerfModel
 from .session import run_trace

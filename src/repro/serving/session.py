@@ -109,7 +109,8 @@ from ..core import lifecycle
 from ..core.arbiter import Arbiter, LeastSlackArbiter
 from ..core.policies import Policy
 from ..core.request import Request
-from .backend import Backend, BackendError, ServerLog, run_label
+from . import tracing
+from .backend import Backend, BackendError, ServerLog
 from .metrics import ServeStats
 from .registry import ModelEntry, ModelRegistry
 from .traffic import Trace
@@ -502,6 +503,8 @@ class ServingSession:
         instant, not a stale timestamp). ``on_token(handle, token)`` fires
         once per response token at the producing run's boundary.
         """
+        if tracing.ON:
+            tracing.stamp(tracing.SUBMIT, rid=req.rid)
         if req.rid in self.handles:
             raise ValueError(f"rid {req.rid} already submitted — clone the "
                              f"request to resubmit the same trace entry")
@@ -792,9 +795,14 @@ class ServingSession:
         model's formed batch simply stays parked (its policy returns the
         same work next step) and burns waiting time until the arbiter
         picks it."""
+        step = tracing.ON and tracing.begin(tracing.STEP)
+        sp = tracing.ON and tracing.begin(tracing.SCHED_ADMIT)
         self._enqueue_due()
         if self.cancel_expired:
             self._expire_due()
+        if sp:
+            tracing.end(sp)
+        sp = tracing.ON and tracing.begin(tracing.SCHED_POLICY)
         entries = self.registry.entries()
         candidates: List[Tuple[ModelEntry, object, Tuple[str, ...]]] = []
         for entry in entries:
@@ -802,21 +810,12 @@ class ServingSession:
             if work is not None:
                 candidates.append((entry, work[0], work[1]))
         if not candidates:
-            nxt = []
-            if self._arrivals:
-                nxt.append(self._arrivals[0][0])
-            for entry in entries:
-                t = entry.policy.next_timer(self.now)
-                if t is not None:
-                    nxt.append(max(t, self.now))
-            if not nxt:
-                return False                      # fully drained
-            target = min(nxt)
-            if limit is not None and target > limit:
-                self.now = max(self.now, limit)
-                return False
-            self.now = target
-            return True
+            moved = self._advance_idle(entries, limit)
+            if step:
+                tracing.end(step)
+            return moved
+        if sp:
+            tracing.end(sp)
 
         if len(entries) == 1:          # single-model: bit-exact legacy path
             entry, sb, run = candidates[0]
@@ -824,30 +823,33 @@ class ServingSession:
             # multi-model sessions consult the arbiter even for a single
             # candidate so stateful arbiters (round-robin's cursor) see
             # every dispatch, not just the contended ones
+            sp = tracing.ON and tracing.begin(tracing.SCHED_ARBITER)
             entry, sb, run = candidates[self.arbiter.pick(candidates,
                                                           self.now)]
+            if sp:
+                tracing.end(sp)
         reqs = list(sb.live_requests)
         try:
-            latency, per_node = self.backend.execute_run(entry.name, sb, run)
+            latency, _ = self.backend.execute_run(entry.name, sb, run)
         except BackendError as err:
             if self.retry is None:
+                if step:
+                    tracing.end(step)
                 raise       # no retry policy armed: pre-failure-model
+            sp = tracing.ON and tracing.begin(tracing.SCHED_EPILOGUE)
             self._on_fault(entry, sb, reqs, err)
             if self.on_run_boundary is not None:
                 self.on_run_boundary(self, entry.name, [])
+            if step:
+                tracing.end(step)
             return True
+        sp = tracing.ON and tracing.begin(tracing.SCHED_EPILOGUE)
         self.log.nodes_executed += len(run)
         self.log.runs_executed += 1
         self.log.busy_time += latency
         self.log.batch_size_sum += sb.size * len(run)
         self.log.busy_by_model[entry.name] = (
             self.log.busy_by_model.get(entry.name, 0.0) + latency)
-        prefix = f"{entry.name}:" if len(entries) > 1 else ""
-        if per_node is not None:
-            for nid, lat in zip(run, per_node):
-                self.log.record(prefix + nid, lat)
-        else:
-            self.log.record(prefix + run_label(run), latency, n=len(run))
         self.now += latency
         done_now = entry.policy.work_done(sb, self.now, len(run))
         # observe (stream tokens, stamp TTFT) BEFORE the completion hooks:
@@ -864,6 +866,28 @@ class ServingSession:
                                ok=(dl is None or r.latency() <= dl + 1e-12))
         if self.on_run_boundary is not None:
             self.on_run_boundary(self, entry.name, done_now)
+        if step:
+            tracing.end(step)
+        return True
+
+    def _advance_idle(self, entries, limit: Optional[float]) -> bool:
+        """No run is ready: jump the clock to the next event (arrival or
+        earliest policy timer), no further than ``limit``. ``False`` when
+        fully drained or the next event lies beyond ``limit``."""
+        nxt = []
+        if self._arrivals:
+            nxt.append(self._arrivals[0][0])
+        for entry in entries:
+            t = entry.policy.next_timer(self.now)
+            if t is not None:
+                nxt.append(max(t, self.now))
+        if not nxt:
+            return False                          # fully drained
+        target = min(nxt)
+        if limit is not None and target > limit:
+            self.now = max(self.now, limit)
+            return False
+        self.now = target
         return True
 
     def _observe(self, entry: ModelEntry, req: Request):

@@ -100,6 +100,7 @@ class _FaultNth(SimExecutor):
         self.fault_on = set(fault_on)
         self.latency = latency
         self.dispatch = 0
+        self.committed_s = 0.0       # latency of the runs that completed
 
     def execute_run(self, model, sb, node_ids):
         self.dispatch += 1
@@ -107,7 +108,9 @@ class _FaultNth(SimExecutor):
             raise TransientBackendError(
                 f"injected on dispatch {self.dispatch}",
                 latency=self.latency)
-        return super().execute_run(model, sb, node_ids)
+        out = super().execute_run(model, sb, node_ids)
+        self.committed_s += out[0]
+        return out
 
 
 def test_transient_faults_retry_to_completion():
@@ -139,9 +142,10 @@ def test_fault_latency_burns_device_time_without_committing_nodes():
     session.drain()
     assert h.state is HandleState.DONE
     # the faulted dispatch's detection latency is in busy_time, but its
-    # nodes were never committed (node_lat only has the re-run's entries)
-    assert session.log.busy_time > sum(
-        nl.total for nl in session.log.node_lat.values()) + 1.9 * MS
+    # nodes were never committed: nodes_executed counts only the re-run's
+    assert session.log.busy_time == pytest.approx(
+        backend.committed_s + 2 * MS)
+    assert session.log.nodes_executed == len(h.request.sequence)
     assert session.log.faults == 1
 
 

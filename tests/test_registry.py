@@ -307,9 +307,11 @@ def test_round_robin_alternates_between_backlogged_models():
     # Serial commits one whole graph per run: with both models backlogged
     # the round-robin arbiter strictly alternates their runs
     assert dispatch_order == ["a", "b"] * 3
-    # multi-model sessions prefix per-run log keys with the model name
-    assert any(k.startswith("a:") for k in session.log.node_lat)
-    assert any(k.startswith("b:") for k in session.log.node_lat)
+    # multi-model sessions attribute each run's device time to its model
+    assert set(session.log.busy_by_model) == {"a", "b"}
+    assert all(t > 0 for t in session.log.busy_by_model.values())
+    assert sum(session.log.busy_by_model.values()) == \
+        pytest.approx(session.log.busy_time)
 
 
 def test_submit_model_resolution_and_validation():

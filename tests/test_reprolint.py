@@ -459,6 +459,54 @@ def test_contract_requires_residency_pair(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# the span recorder is an audited wall-clock boundary
+# ---------------------------------------------------------------------------
+
+RECORDER = """
+import time
+
+
+def begin(name):
+    return (name, time.perf_counter())
+"""
+
+SESSION_CALLS_RECORDER = """
+from repro.serving import tracing
+
+
+class ServingSession:
+    def step(self):
+        sp = tracing.ON and tracing.begin("serve.step")
+        return sp
+"""
+
+SESSION_READS_CLOCK = """
+import time
+
+
+class ServingSession:
+    def step(self):
+        return time.perf_counter()
+"""
+
+
+def test_recorder_is_audited_but_session_clock_reads_still_fire(tmp_path):
+    from repro.analysis.lint import PROJECT_CHECKERS
+    rec = _write(tmp_path, "src/repro/serving/tracing.py", RECORDER)
+    calls = _write(tmp_path / "a", "src/repro/serving/session.py",
+                   SESSION_CALLS_RECORDER)
+    res = run_lint([rec, calls], checkers=[],
+                   project_checkers=PROJECT_CHECKERS)
+    assert res.new == []
+    reads = _write(tmp_path / "b", "src/repro/serving/session.py",
+                   SESSION_READS_CLOCK)
+    res = run_lint([rec, reads], checkers=[],
+                   project_checkers=PROJECT_CHECKERS)
+    assert [(f.checker, f.path) for f in res.new] == \
+        [("wallclock-taint", "repro/serving/session.py")]
+
+
+# ---------------------------------------------------------------------------
 # the --cache layer
 # ---------------------------------------------------------------------------
 
