@@ -135,6 +135,8 @@ class SanitizerStats:
     host_syncs: int = 0          # run-boundary sync events (<= runs)
     retraces: int = 0            # jit traces = XLA compiles triggered
     max_syncs_per_run: int = 0   # worst single run (contract: <= 1)
+    lookahead_dispatched: int = 0  # decode cycles dispatched a run ahead
+    lookahead_hits: int = 0        # of those, taken by the next run
 
     @property
     def ok(self) -> bool:
@@ -315,6 +317,8 @@ class MultiBackend(Backend):
             agg.retraces += st.retraces
             agg.max_syncs_per_run = max(agg.max_syncs_per_run,
                                         st.max_syncs_per_run)
+            agg.lookahead_dispatched += st.lookahead_dispatched
+            agg.lookahead_hits += st.lookahead_hits
         return agg
 
 

@@ -74,6 +74,28 @@ dispatches each chunk as ONE jitted call:
     inside a run chain on device and the engine synchronizes ONCE at the
     run boundary (the scheduler-visible point), so the server clock
     measures run latency, not per-node latency.
+  * **one-cycle decode lookahead** — when a run is exactly one full
+    decode cycle with its head and the batch will run another, the next
+    cycle is dispatched before the run's sync, from the device-carried
+    tokens at ``pos + 1`` with the same context-bucket rule (no new
+    program). The sync then reads only this cycle's tokens (the program
+    that sampled them wrote this cycle's arena rows, so the readback
+    fences them), and the device runs the next cycle while the host does
+    the epilogue, the session streams tokens and the scheduler decides.
+    The next run takes that result when it is the same cycle for the
+    same batch (same rids, ``Bp`` and positions, no arena change
+    between); anything else discards it and runs as without lookahead.
+    A run that takes the queued cycle reports its latency from the end of
+    the last run on the device (any engine's), where the device started
+    on it, and not from its own entry: the host's work between the runs
+    (epilogue, token streaming, scheduling) then counts in that run's
+    latency and in ``ServerLog.busy_time``, since the device was busy
+    through it, and the session's clock keeps level with the wall.
+    A discarded cycle only wrote the KV row at ``pos + 1`` of its own
+    rows, which any real run at that position rewrites before reading
+    it, so lookahead is limited to stacks whose every arena leaf is a
+    position-indexed cache row (no recurrent state) and to runs where
+    no row finishes.
 
 ``execute(sb, node_id)`` (single-node dispatch, one blocking device call
 per node) remains fully supported — it is the degenerate run and the
@@ -161,6 +183,10 @@ class JaxEngine(Backend):
     Defaults to on for accelerator backends, off for CPU (interpret mode
     is functional but slow).
     """
+
+    # end of the last run any engine of this process executed: they share
+    # its device, and a queued decode cycle runs there from that point
+    _last_run_end = 0.0
 
     def __init__(self, cfg: ModelConfig, *, max_len: int = 512, seed: int = 0,
                  dtype=jnp.float32, n_slots: Optional[int] = None,
@@ -280,6 +306,18 @@ class JaxEngine(Backend):
                                         + l.shape[1:], l.dtype), one))
         else:
             self.arenas = []
+        # one-cycle decode lookahead (execute_run): the next cycle of a
+        # steady batch, dispatched before the run's sync, as
+        # (((rids, Bp), host positions it runs at), device tokens) or
+        # None; only stacks whose arena leaves are all position-indexed
+        # cache rows can run a cycle twice harmlessly
+        self._ahead: Optional[tuple] = None
+        self._lookahead = bool(self.arenas) and self.fused and all(
+            _is_time_leaf(path) for span in self.arenas
+            for path, _ in jax.tree_util.tree_leaves_with_path(span))
+        self._full_cycle = ("decode", 0, len(self.kinds) - 1, True)
+        self._san_lookahead_dispatched = 0
+        self._san_lookahead_hits = 0
 
     # ------------------------------------------------------------------
     # Request registration / slot lifecycle
@@ -364,6 +402,7 @@ class JaxEngine(Backend):
                 span_len * new, *l.shape[1:])
 
         self.arenas = [jax.tree.map(grow, span) for span in self.arenas]
+        self._ahead = None
         self.n_slots = new
         self.n_grows += 1
         self._free_slots.extend(range(old, self.n_slots))
@@ -427,6 +466,7 @@ class JaxEngine(Backend):
             self.arenas[si] = jax.tree.map(compact, self.arenas[si])
         self.n_slots = target
         self.n_shrinks += 1
+        self._ahead = None
         used = set(self._slot.values())
         self._free_slots = deque(s for s in range(target) if s not in used)
         # slot ids moved: the membership-keyed slot vector is stale (pos /
@@ -494,7 +534,9 @@ class JaxEngine(Backend):
             runs=self.runs_executed,
             host_syncs=self._san_host_syncs,
             retraces=self._san_retraces,
-            max_syncs_per_run=self._san_max_syncs_per_run)
+            max_syncs_per_run=self._san_max_syncs_per_run,
+            lookahead_dispatched=self._san_lookahead_dispatched,
+            lookahead_hits=self._san_lookahead_hits)
 
     def _note_trace(self):
         """Called from INSIDE jitted bodies: executes only at trace time,
@@ -550,6 +592,7 @@ class JaxEngine(Backend):
     # ------------------------------------------------------------------
     def _flush_xbatch(self):
         if self._xbatch is not None:
+            self._ahead = None
             rids, x = self._xbatch
             for bi, rid in enumerate(rids):
                 st = self.states.get(rid)
@@ -949,6 +992,7 @@ class JaxEngine(Backend):
                 tracing.count(tracing.DECODE_CYCLES, heads)
                 tracing.end(run, rids=[r.rid for r in reqs], rows=len(reqs),
                             padded=len(reqs), cycles=heads)
+            JaxEngine._last_run_end = time.perf_counter()
             return out
         t0 = time.perf_counter()
         run = tracing.ON and tracing.begin(tracing.ENGINE_RUN)
@@ -962,6 +1006,7 @@ class JaxEngine(Backend):
             # only in the batched cache — flush rows to per-request state
             # before this run's epilogue clobbers it
             self._flush_xbatch()
+        ahead, self._ahead = self._ahead, None    # taken here, or discarded
         B = len(reqs)
         Bp = _pow2(B)
         pos0 = None
@@ -984,6 +1029,12 @@ class JaxEngine(Backend):
                        + (1 if trailing else 0))
             ctx = min(_pow2(deepest), self.max_len)
         bkey = (rids, Bp)
+        n = len(node_ids)
+        finishing = [r for r in reqs if r.idx + n >= len(r.sequence)]
+        # one full decode cycle with its head: the run lookahead repeats
+        cycle = chunks == [self._full_cycle]
+        hit = (cycle and ahead is not None
+               and ahead[0] == (bkey, tuple(st.pos for st in sts)))
         if plan:
             tracing.end(plan)
         for ch in chunks:
@@ -999,27 +1050,32 @@ class JaxEngine(Backend):
                 else:
                     pos0 = jnp.asarray([st.pos for st in sts]
                                        + [0] * (Bp - B), jnp.int32)
-            pos = pos0 if n_heads == 0 else pos0 + n_heads
-            if lo == 0:
-                if toks_dev is None and self._tokbatch is not None \
-                        and self._tokbatch[0] == bkey:
-                    toks_dev = self._tokbatch[1]  # device-carried tokens
-                entry = (toks_dev if toks_dev is not None else
-                         jnp.asarray([st.next_token for st in sts]
-                                     + [0] * (Bp - B), jnp.int32))
-            else:
-                entry = x_dev if x_dev is not None \
-                    else self._entry_x(reqs, sts, B, Bp)
-            fn = self._fn_mega(lo, hi, with_head, ctx)
             offs = self._offs()
-            if plan:
-                tracing.end(plan)
-            sp = tracing.ON and tracing.begin(tracing.ENGINE_DISPATCH,
-                                              program="decode_megastep")
-            out, self.arenas = fn(self.params, self._span_params,
-                                  self.arenas, entry, pos, slots, offs)
-            if sp:
-                tracing.end(sp)
+            if hit:
+                # dispatched by the previous run and run on the device
+                # since the last run ended: its latency starts there, so
+                # the session's clock keeps the device's time
+                out, t0 = ahead[1], JaxEngine._last_run_end
+                self._san_lookahead_hits += 1
+                if plan:
+                    tracing.end(plan)
+                    tracing.count(tracing.LOOKAHEAD_HITS, 1)
+            else:
+                pos = pos0 if n_heads == 0 else pos0 + n_heads
+                if lo == 0:
+                    if toks_dev is None and self._tokbatch is not None \
+                            and self._tokbatch[0] == bkey:
+                        toks_dev = self._tokbatch[1]  # device-carried
+                    entry = (toks_dev if toks_dev is not None else
+                             jnp.asarray([st.next_token for st in sts]
+                                         + [0] * (Bp - B), jnp.int32))
+                else:
+                    entry = x_dev if x_dev is not None \
+                        else self._entry_x(reqs, sts, B, Bp)
+                if plan:
+                    tracing.end(plan)
+                out = self._dispatch_mega(lo, hi, with_head, ctx, entry,
+                                          pos, slots, offs)
             if with_head:
                 head_toks.append(out)
                 toks_dev = out
@@ -1027,11 +1083,27 @@ class JaxEngine(Backend):
                 n_heads += 1
             else:
                 x_dev = out
+        pos_next = pos0 + n_heads if n_heads and pos0 is not None else None
+        nxt = None
+        # ---- lookahead: the batch's next cycle, queued behind this one
+        # at pos + 1 under the context bucket its own run would pick
+        # (``deepest`` + 1), so the device is busy through the sync
+        if (cycle and self._lookahead and not finishing
+                and deepest < self.max_len):
+            nxt = self._dispatch_mega(0, hi, True,
+                                      min(_pow2(deepest + 1), self.max_len),
+                                      toks_dev, pos_next, slots, offs)
+            self._san_lookahead_dispatched += 1
+            if tracing.ON:
+                tracing.count(tracing.LOOKAHEAD_DISPATCHED, 1)
         # ---- run boundary: the ONLY sync point -----------------------
         sp = tracing.ON and tracing.begin(tracing.ENGINE_SYNC)
         # reprolint: disable=sync-point
         arrs = [np.asarray(t) for t in head_toks]
-        jax.block_until_ready(self.arenas)  # reprolint: disable=sync-point
+        if nxt is None:
+            # (with a cycle queued the arenas are its output, and the
+            # readback above already fenced this cycle's writes)
+            jax.block_until_ready(self.arenas)  # reprolint: disable=sync-point
         # token readback + arena fence at ONE run boundary are a single
         # logical sync event: the one-sync-per-run contract
         self._san_host_syncs += 1
@@ -1044,24 +1116,38 @@ class JaxEngine(Backend):
                 st.next_token = int(arr[bi])  # reprolint: disable=sync-point
                 st.generated.append(st.next_token)
                 st.pos += 1
-        if n_heads and pos0 is not None:
-            self._posbatch = (bkey, pos0 + n_heads)
+        if pos_next is not None:
+            self._posbatch = (bkey, pos_next)
             self._tokbatch = (bkey, toks_dev)
         if x_dev is not None:
             self._xbatch = (rids, x_dev[:B])      # run ended mid-cycle
         else:
             self._xbatch = None
-        self.nodes_executed += len(node_ids)
+        self.nodes_executed += n
         self.runs_executed += 1
-        n = len(node_ids)
-        self._release_slots([r for r in reqs
-                             if r.idx + n >= len(r.sequence)])  # final node
+        self._release_slots(finishing)            # their final node ran
         if sp:
             tracing.end(sp)
             tracing.count(tracing.DECODE_CYCLES, n_heads)
             tracing.end(run, rids=list(rids), rows=B, padded=Bp,
                         cycles=n_heads)
-        return time.perf_counter() - t0, None
+        if nxt is not None:
+            self._ahead = ((bkey, tuple(st.pos for st in sts)), nxt)
+        t1 = JaxEngine._last_run_end = time.perf_counter()
+        return t1 - t0, None
+
+    def _dispatch_mega(self, lo, hi, with_head, ctx, entry, pos, slots,
+                       offs):
+        """Dispatch one decode megastep chunk over the arenas; returns
+        its output (head tokens, or the activations of a headless chunk)."""
+        fn = self._fn_mega(lo, hi, with_head, ctx)
+        sp = tracing.ON and tracing.begin(tracing.ENGINE_DISPATCH,
+                                          program="decode_megastep")
+        out, self.arenas = fn(self.params, self._span_params, self.arenas,
+                              entry, pos, slots, offs)
+        if sp:
+            tracing.end(sp)
+        return out
 
     def _entry_x(self, reqs, sts, B, Bp):
         rids, x = self._batched_x(reqs, sts)
@@ -1075,6 +1161,7 @@ class JaxEngine(Backend):
     # ------------------------------------------------------------------
     def execute(self, model, sb: SubBatch, node_id: str) -> float:
         t0 = time.perf_counter()
+        self._ahead = None
         reqs = sb.live_requests
         outs = []
         phase, i = self._node_meta(reqs[0].workload, node_id)

@@ -53,6 +53,8 @@ ENGINE_EPILOGUE = "serve.engine.epilogue"
 PREFILL_TOKENS = "prefill_tokens"        # real prompt tokens prefilled
 PREFILL_POSITIONS = "prefill_positions"  # positions dispatched (Bp x Lb)
 DECODE_CYCLES = "decode_cycles"          # heads run
+LOOKAHEAD_DISPATCHED = "lookahead_dispatched"  # next cycles run ahead
+LOOKAHEAD_HITS = "lookahead_hits"        # of those, taken by the next run
 
 CAPACITY = 1 << 19
 

@@ -143,12 +143,16 @@ def test_engine_run_spans_counters_and_one_sync():
     spans = rec.spans
     runs = [i for i, s in enumerate(spans) if s.name == tracing.ENGINE_RUN]
     assert runs and engine.sanitizer_stats().max_syncs_per_run == 1
+    hits = [t for name, t, _ in rec.counters
+            if name == tracing.LOOKAHEAD_HITS]
     for i in runs:
         assert spans[spans[i].parent].name == tracing.STEP
         kids = [s.name for s in spans if s.parent == i]
         assert kids[-2:] == [tracing.ENGINE_SYNC, tracing.ENGINE_EPILOGUE]
         assert kids[0] == tracing.ENGINE_PLAN
-        assert tracing.ENGINE_DISPATCH in kids
+        # a run dispatches, or takes the cycle the run before dispatched
+        assert tracing.ENGINE_DISPATCH in kids or any(
+            spans[i].t0 <= t <= spans[i].t1 for t in hits)
         assert spans[i].attrs["rows"] == len(spans[i].attrs["rids"])
     programs = {s.attrs["program"] for s in spans
                 if s.name == tracing.ENGINE_DISPATCH}
@@ -176,3 +180,39 @@ def test_engine_run_spans_counters_and_one_sync():
     assert total[tracing.DECODE_CYCLES] == sum(
         s.attrs["cycles"] for s in (spans[i] for i in runs)) == 3
     assert all(len(h.tokens) == 3 for h in hs)
+
+
+def _totals(counters):
+    total = {}
+    for name, _, n in counters:
+        total[name] = total.get(name, 0) + n
+    return total
+
+
+def test_lookahead_counts_add_up_with_decode_cycles():
+    engine, wl = _tiny_engine()
+    tracing.enable()
+    _serve_engine(engine, wl)
+    rec = tracing.records()
+    total = _totals(rec.counters)
+    cycles = total[tracing.DECODE_CYCLES]
+    ahead = total[tracing.LOOKAHEAD_DISPATCHED]
+    hits = total[tracing.LOOKAHEAD_HITS]
+    # three requests of three tokens decode as one batch: the first two
+    # cycles each queue the next, which the following run takes
+    assert (cycles, ahead, hits) == (3, 2, 2)
+    stats = engine.sanitizer_stats()
+    assert (stats.lookahead_dispatched, stats.lookahead_hits) == (ahead, hits)
+    # every megastep dispatched is a cycle run for itself or run ahead
+    mega = sum(1 for s in rec.spans if s.name == tracing.ENGINE_DISPATCH
+               and s.attrs["program"] == "decode_megastep")
+    assert mega == cycles - hits + ahead
+    # off, the same sites record nothing while the engine still counts
+    tracing.disable()
+    tracing.reset()
+    engine, wl = _tiny_engine()
+    _serve_engine(engine, wl)
+    rec = tracing.records()
+    assert rec.spans == [] and rec.counters == []
+    stats = engine.sanitizer_stats()
+    assert (stats.lookahead_dispatched, stats.lookahead_hits) == (2, 2)
