@@ -21,6 +21,7 @@ from typing import Callable, List, Optional, Set, Tuple
 import numpy as np
 
 from . import traffic as T
+from .work import published
 
 DTYPE_NAMES = ("bfloat16", "float32")
 
@@ -40,25 +41,123 @@ def pow2(n: int) -> int:
 # Program construction
 # ---------------------------------------------------------------------------
 
+# How a configuration file's keys meet the program's ``ModelConfig``, by
+# the kind that defines them: file key -> (program attribute, dotted; what
+# the program computes as where it has no such attribute, or a function of
+# its ``ModelConfig`` that gives it). ``REQUIRED``:
+# the file states it and the program holds it. ``FIELD``: the file may
+# state it; then the program has to hold it.
+REQUIRED, FIELD = "required", "field"
+KIND_KEYS = {
+    "common": {"hidden_size": ("d_model", REQUIRED),
+               "num_attention_heads": ("num_heads", REQUIRED),
+               "vocab_size": ("vocab_size", REQUIRED),
+               "rope_theta": ("rope_theta", REQUIRED),
+               "rms_norm_eps": ("norm_eps", REQUIRED),
+               "tie_word_embeddings": ("tie_embeddings", REQUIRED),
+               "attention_bias": ("qkv_bias", FIELD),
+               "hidden_act": ("hidden_act", "silu"),     # SwiGLU
+               "rope_scaling": ("rope_scaling", None)},  # plain RoPE
+    "gqa": {"num_key_value_heads": ("num_kv_heads", REQUIRED),
+            "head_dim": ("head_dim", REQUIRED)},
+    "mla": {"num_key_value_heads": ("num_kv_heads", FIELD),
+            "q_lora_rank": ("mla.q_lora_rank", REQUIRED),
+            "kv_lora_rank": ("mla.kv_lora_rank", REQUIRED),
+            "qk_nope_head_dim": ("mla.qk_nope_head_dim", REQUIRED),
+            "qk_rope_head_dim": ("mla.qk_rope_head_dim", REQUIRED),
+            "v_head_dim": ("mla.v_head_dim", REQUIRED)},
+    "dense": {"intermediate_size": ("d_ff", REQUIRED)},
+    # every expert lives on the chip unless the program holds a share
+    "moe": {"n_routed_experts": ("moe.experts_held",
+                                 lambda cfg: cfg.moe.num_experts),
+            "num_experts_per_tok": ("moe.experts_per_token", REQUIRED),
+            "moe_intermediate_size": ("d_ff", REQUIRED),
+            "intermediate_size": ("moe.dense_intermediate_size", FIELD),
+            "n_shared_experts": ("moe.n_shared_experts", 0),
+            "first_k_dense_replace": ("moe.first_k_dense_replace", 0),
+            "moe_layer_freq": ("moe.moe_layer_freq", 1),
+            "scoring_func": ("moe.scoring_func", "softmax"),
+            "norm_topk_prob": ("moe.norm_topk_prob", True),
+            "routed_scaling_factor": ("moe.routed_scaling_factor", 1),
+            "topk_method": ("moe.topk_method", "greedy"),
+            "n_group": ("moe.n_group", 1),
+            "topk_group": ("moe.topk_group", 1)},
+}
+# keys that describe the file or the run, not the model's arithmetic
+# (``seq_aux`` switches a training loss; depth is the cut, set below)
+DESCRIPTIVE = {"name", "source", "arch", "attention", "reference", "dtype",
+               "torch_dtype", "num_hidden_layers", "max_position_embeddings",
+               "model_type", "seq_aux", "reduced", "assumed", "deployment",
+               "serving", "aot_v5e"}
+_ABSENT = object()
+
+
+def _attr(obj, path: str):
+    for part in path.split("."):
+        obj = getattr(obj, part, _ABSENT)
+        if obj is _ABSENT:
+            break
+    return obj
+
+
 def program_config(config: dict):
     """The program's ``ModelConfig`` for a configuration file, cut to the
-    file's depth. The program's widths must equal the file's: a program
-    whose model drifted from the configuration is refused, not run."""
+    file's depth. Every key the file states is held to the program, by
+    the kinds of attention (``gqa``, ``mla``) and feed-forward (dense, or
+    experts where the file states ``n_routed_experts``) that it defines.
+    A program whose model drifted from the file, a key the program has no
+    attribute for (unless the file's value is what the program computes
+    without one), a key no kind of the file defines, and a program kind
+    this check does not know are refused, not run."""
     from repro.configs import get_config
     cfg = get_config(config["arch"])
-    want = {"d_model": "hidden_size", "num_heads": "num_attention_heads",
-            "num_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
-            "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-            "rope_theta": "rope_theta", "norm_eps": "rms_norm_eps",
-            "tie_embeddings": "tie_word_embeddings"}
-    for attr, key in want.items():
-        if getattr(cfg, attr) != config[key]:
-            raise ValueError(f"program {config['arch']}.{attr}="
-                             f"{getattr(cfg, attr)} differs from the "
-                             f"configuration's {key}={config[key]}")
-    if cfg.attention != config["attention"] or cfg.mla or cfg.moe \
-            or cfg.hybrid:
-        raise ValueError("program attention kind differs from the config")
+    arch = config["arch"]
+    if cfg.hybrid is not None or cfg.ssm is not None:
+        raise ValueError(f"program {arch} is a hybrid or state-space model; "
+                         f"no configuration kind checks it")
+    if cfg.attention != config["attention"] \
+            or cfg.attention not in ("gqa", "mla"):
+        raise ValueError(f"program {arch}.attention={cfg.attention!r}, the "
+                         f"configuration's {config['attention']!r}")
+    moe = "n_routed_experts" in config
+    if (cfg.moe is not None) != moe:
+        raise ValueError(f"program {arch}.moe={cfg.moe}, while the "
+                         f"configuration {'states' if moe else 'lacks'} "
+                         f"n_routed_experts")
+    kinds = ("common", cfg.attention, "moe" if moe else "dense")
+    keys = {k: v for kind in kinds for k, v in KIND_KEYS[kind].items()}
+    foreign = {k for table in KIND_KEYS.values() for k in table}
+    for key in keys:
+        if keys[key][1] == REQUIRED and key not in config:
+            raise ValueError(f"the configuration lacks {key}, which a "
+                             f"{'/'.join(kinds)} model states")
+    for key, want in config.items():
+        if key in DESCRIPTIVE:
+            continue
+        if key not in keys:
+            what = "foreign to" if key in foreign else "unknown to"
+            raise ValueError(f"configuration key {key} is {what} a "
+                             f"{'/'.join(kinds)} model")
+        path, without = keys[key]
+        got = _attr(cfg, path)
+        if got is not _ABSENT:
+            if got != want:
+                raise ValueError(f"program {arch}.{path}={got!r} differs "
+                                 f"from the configuration's {key}={want!r}")
+            continue
+        if without not in (REQUIRED, FIELD):
+            got = without(cfg) if callable(without) else without
+            if got == want:
+                continue
+        raise ValueError(f"program {arch} has no {path} for the "
+                         f"configuration's {key}={want!r}"
+                         + ("" if without in (REQUIRED, FIELD)
+                            else f" (it computes as {got!r})"))
+    if moe and cfg.moe.num_experts != published(config, "n_routed_experts"):
+        raise ValueError(f"program {arch}.moe.num_experts="
+                         f"{cfg.moe.num_experts} differs from the published "
+                         f"n_routed_experts "
+                         f"{published(config, 'n_routed_experts')}")
     return dataclasses.replace(cfg, num_layers=config["num_hidden_layers"])
 
 

@@ -102,7 +102,12 @@ def forward_logits(dims: dict, seed: int, seqs: Sequence[np.ndarray],
     one key per layer, two per block), and every sequence is carried
     through that layer before the next is drawn. Sequences are padded at
     the end to a multiple of ``pad_to``; causal attention keeps padding
-    out of every real position."""
+    out of every real position.
+
+    Layer ``i`` is ``layer_weights(key, dims, dtype, i)`` and
+    ``block(lin, w, x, dims, i)``, the index a Python int, so a model
+    whose layers differ (a leading dense layer before expert layers)
+    picks each layer's weights and equation by it."""
     dtype = DTYPES[dims["dtype"]]
     d, eps = dims["hidden_size"], dims["rms_norm_eps"]
     k_emb, k_blocks, k_head, _ = jax.random.split(jax.random.key(seed), 4)
@@ -119,10 +124,12 @@ def forward_logits(dims: dict, seed: int, seqs: Sequence[np.ndarray],
     else:
         del table
         head = draw(k_head, (d, dims["vocab_size"]), 1 / math.sqrt(d), dtype)
-    step = jax.jit(lambda w, x: block(lin, w, x, dims))
-    for bk in jax.random.split(k_blocks, dims["num_hidden_layers"]):
-        w = layer_weights(bk, dims, dtype)
-        xs = [step(w, x) for x in xs]
+    step = jax.jit(lambda w, x, i: block(lin, w, x, dims, i),
+                   static_argnums=2)
+    for i, bk in enumerate(jax.random.split(k_blocks,
+                                            dims["num_hidden_layers"])):
+        w = layer_weights(bk, dims, dtype, i)
+        xs = [step(w, x, i) for x in xs]
         del w
     out = []
     for x, pos in zip(xs, want):

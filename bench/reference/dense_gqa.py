@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from .common import causal_softmax_av, draw, mlp, mlp_weights, rms_norm, rope
 
 
-def layer_weights(key, dims: dict, dtype) -> dict:
+def layer_weights(key, dims: dict, dtype, layer: int) -> dict:
     d, h = dims["hidden_size"], dims["num_attention_heads"]
     kv, hd = dims["num_key_value_heads"], dims["head_dim"]
     k_attn, k_mlp = jax.random.split(key)
@@ -23,8 +23,8 @@ def layer_weights(key, dims: dict, dtype) -> dict:
             "mlp": mlp_weights(k_mlp, d, dims["intermediate_size"], dtype)}
 
 
-def block(lin, w, x, dims: dict):
-    """One layer over a whole sequence x (S, d)."""
+def block(lin, w, x, dims: dict, layer: int):
+    """One layer over a whole sequence x (S, d); every layer alike."""
     eps, theta = dims["rms_norm_eps"], dims["rope_theta"]
     h, kv = dims["num_attention_heads"], dims["num_key_value_heads"]
     pos = jnp.arange(x.shape[0])
